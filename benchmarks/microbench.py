@@ -27,7 +27,7 @@ def run() -> List[Row]:
     w = jax.random.normal(jax.random.PRNGKey(1), (512, 256)) * 0.05
     q = quantize_int8(w)
     f_kernel = jax.jit(lambda a: int8_matmul_pallas(
-        a, q.codes, q.scale, bm=64, bn=256, bk=256))
+        a, q.codes, q.scale, bm=64, bn=256, bk=256, interpret=True))
     f_ref = jax.jit(lambda a: jnp.dot(
         a, q.codes.astype(jnp.float32) * q.scale[None, :]))
     f_kernel(x).block_until_ready()
@@ -45,7 +45,7 @@ def run() -> List[Row]:
     kk = jax.random.normal(jax.random.PRNGKey(2), (B, S, Kv, d))
     vv = jax.random.normal(jax.random.PRNGKey(3), (B, S, Kv, d))
     f_fl = jax.jit(lambda a, b, c: flash_attention_pallas(
-        a, b, c, bq=64, bkv=64))
+        a, b, c, bq=64, bkv=64, interpret=True))
     f_fl(qq, kk, vv).block_until_ready()
     rows.append(Row("micro/flash_attention_interpret",
                     timeit(lambda: f_fl(qq, kk, vv).block_until_ready()),

@@ -133,6 +133,9 @@ def main(argv=None) -> None:
         _list_suites()
         return
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     benches = [(n, mod.run) for n, mod in _benches()]
     if args.only:
         want = {w.strip() for w in args.only.split(",")}

@@ -775,9 +775,12 @@ class ExperimentSpec:
             if backend == "executed":
                 import jax
                 from repro.models import build_model
-                model = build_model(cfg, fmt=kw["fmt"])
-                exec_kw = dict(execute=True, model=model,
-                               params=model.init(jax.random.PRNGKey(0)),
+                # on a TPU, quantized matmuls run the compiled kernel
+                on_tpu = jax.devices()[0].platform == "tpu"
+                model = build_model(cfg, fmt=kw["fmt"],
+                                    use_pallas_kernels=on_tpu)
+                params = model.quantize(model.init(jax.random.PRNGKey(0)))
+                exec_kw = dict(execute=True, model=model, params=params,
                                buf_len=self.buf_len)
             elif backend == "replay":
                 exec_kw = dict(

@@ -234,9 +234,36 @@ TPU_V5E = DeviceSpec(
 
 DEVICES = {d.name: d for d in (H100_SXM, TPU_V5E)}
 
+#: ``jax.Device.device_kind`` -> the DeviceSpec of that chip; the one
+#: place a real accelerator is mapped to its constants (a v5e reports
+#: itself as "TPU v5 lite")
+DEVICE_KINDS = {"TPU v5 lite": TPU_V5E}
+
 
 def get_device(name: str) -> DeviceSpec:
     try:
         return DEVICES[name]
     except KeyError:
         raise ValueError(f"unknown device {name!r}; known: {list(DEVICES)}")
+
+
+def device_for_kind(kind: str) -> DeviceSpec:
+    try:
+        return DEVICE_KINDS[kind]
+    except KeyError:
+        raise ValueError(f"no DeviceSpec for device_kind {kind!r}; "
+                         f"known: {sorted(DEVICE_KINDS)}")
+
+
+def check_executed_device(device: DeviceSpec, platform: str,
+                          kind: str) -> None:
+    """Refuse an executed run on a TPU that bills another chip's
+    constants (a DVFS-scaled spec counts as its base chip). Runs on
+    other platforms are not checked."""
+    if platform != "tpu":
+        return
+    chip = device_for_kind(kind)
+    if device.name.split("@f")[0] != chip.name:
+        raise ValueError(
+            f"executed run on {kind!r} was given device={device.name!r}; "
+            f"this chip is device={chip.name!r}")

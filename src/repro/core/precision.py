@@ -48,8 +48,8 @@ class PrecisionPolicy:
     outlier_fraction: float = 0.01
     # nf4: quantization block size along the input dim.
     nf4_block_size: int = 64
-    # Route quantized matmuls through the Pallas kernel (tests/benchmarks)
-    # instead of the pure-jnp reference path (dry-run / CPU default).
+    # Route quantized matmuls through the compiled Pallas kernel (the
+    # TPU default of models.build_model) instead of the pure-jnp path.
     use_pallas_kernels: bool = False
 
     # ---- derived quantities used by the energy model -------------------

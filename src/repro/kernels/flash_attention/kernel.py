@@ -67,7 +67,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                            *, causal: bool = True, window=None,
                            bq: int = DEFAULT_BQ, bkv: int = DEFAULT_BKV,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: bool = False) -> jnp.ndarray:
     """q: (B, S, H, d); k/v: (B, T, Kv, d). Returns (B, S, H, d)."""
     B, S, H, d = q.shape
     T, Kv = k.shape[1], k.shape[2]

@@ -1,6 +1,6 @@
 """Pallas TPU paged-attention decode kernel.
 
-vLLM's PagedAttention adapted to TPU (DESIGN.md §2/§7): the KV cache
+vLLM's PagedAttention adapted to TPU: the KV cache
 lives in HBM as a pool of fixed-size pages; each sequence owns a chain of
 pages recorded in a page table. On GPU, paging exploits gather hardware
 inside the kernel; on TPU we express the page lookup as a
@@ -71,7 +71,7 @@ def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 def paged_attention_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
                            v_pages: jnp.ndarray, page_table: jnp.ndarray,
                            seq_lens: jnp.ndarray, *,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: bool = False) -> jnp.ndarray:
     """Decode attention over a paged KV pool.
 
     q:          (B, H, d) — one token per sequence

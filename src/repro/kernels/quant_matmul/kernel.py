@@ -1,6 +1,6 @@
 """Pallas TPU kernels: quantized matmul with on-the-fly VMEM dequant.
 
-TPU adaptation of bitsandbytes (DESIGN.md §2 / §7): the packed integer
+TPU adaptation of bitsandbytes: the packed integer
 tile is dequantized *inside VMEM* (VPU work) and fed straight to the MXU
 in the compute dtype — no HBM round-trip for the 16-bit weights and no
 extra kernel launches, which is precisely the overhead the paper blames
@@ -24,9 +24,9 @@ import numpy as np
 
 from repro.quant.nf4 import NF4_CODEBOOK
 
-# numpy copy of the codebook: a traced jax array may not be closed over
-# inside a pallas kernel body, but a numpy constant is inlined.
-_NF4_LUT = np.asarray(NF4_CODEBOOK, np.float32)
+# the codebook as python floats: the kernel looks codes up with a chain
+# of selects against these constants (the TPU compiler lowers no gather)
+_NF4_LUT = tuple(float(v) for v in np.asarray(NF4_CODEBOOK, np.float32))
 
 DEFAULT_BM = 256
 DEFAULT_BN = 256
@@ -56,7 +56,7 @@ def int8_matmul_pallas(x: jnp.ndarray, codes: jnp.ndarray,
                        scale: jnp.ndarray, *, compute_dtype=jnp.bfloat16,
                        bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
                        bk: int = DEFAULT_BK,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool = False) -> jnp.ndarray:
     """x (M, K) @ dequant(codes (K, N), scale (N,)) -> (M, N)."""
     M, K = x.shape
     N = codes.shape[1]
@@ -84,24 +84,36 @@ def int8_matmul_pallas(x: jnp.ndarray, codes: jnp.ndarray,
 # nf4: packed 2-per-byte, per-(K-block, column) absmax — dequant must
 # happen per K-tile (scales vary along K)
 # ---------------------------------------------------------------------------
-def _nf4_kernel(x_ref, p_ref, a_ref, lut_ref, o_ref, acc_ref, *,
-                compute_dtype, block: int):
+def _nf4_lookup(codes: jnp.ndarray) -> jnp.ndarray:
+    """Codebook values of int32 codes in 0..15, as 15 selects."""
+    vals = jnp.full(codes.shape, _NF4_LUT[0], jnp.float32)
+    for c in range(1, 16):
+        vals = jnp.where(codes == c, _NF4_LUT[c], vals)
+    return vals
+
+
+def _nf4_kernel(xe_ref, xo_ref, p_ref, a_ref, o_ref, acc_ref, *,
+                compute_dtype):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    packed = p_ref[...]                              # (bk//2, bn) uint8
-    lo = (packed & 0x0F).astype(jnp.int32)
-    hi = ((packed >> 4) & 0x0F).astype(jnp.int32)
-    # interleave rows: packing stores even K-rows in the low nibble
-    codes = jnp.stack([lo, hi], axis=1).reshape(
-        packed.shape[0] * 2, packed.shape[1])        # (bk, bn)
-    lut = lut_ref[0]                                 # (16,) in VMEM
-    vals = jnp.take(lut, codes, axis=0)              # (bk, bn) in [-1, 1]
-    absmax = a_ref[...]                              # (bk//block, bn)
-    scale = jnp.repeat(absmax, block, axis=0)        # (bk, bn)
-    w = (vals * scale).astype(compute_dtype)
-    acc_ref[...] += jnp.dot(x_ref[...].astype(compute_dtype), w,
+    # packed tile (blocks, block//2, bn): the low nibbles hold the even
+    # K-rows of each quant block, the high nibbles the odd ones; the
+    # matching activation columns arrive split as xe / xo
+    packed = p_ref[...].astype(jnp.int32)
+    scale = a_ref[...]                               # (blocks, 1, bn)
+    nb, hb, bn = packed.shape
+
+    def dequant(codes):
+        w = _nf4_lookup(codes) * scale               # VMEM dequant (VPU)
+        return w.reshape(nb * hb, bn).astype(compute_dtype)
+
+    acc_ref[...] += jnp.dot(xe_ref[...].astype(compute_dtype),
+                            dequant(packed & 0x0F),
+                            preferred_element_type=jnp.float32)
+    acc_ref[...] += jnp.dot(xo_ref[...].astype(compute_dtype),
+                            dequant(packed >> 4),
                             preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
@@ -113,7 +125,7 @@ def nf4_matmul_pallas(x: jnp.ndarray, packed: jnp.ndarray,
                       absmax: jnp.ndarray, *, compute_dtype=jnp.bfloat16,
                       bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
                       bk: int = DEFAULT_BK,
-                      interpret: bool = True) -> jnp.ndarray:
+                      interpret: bool = False) -> jnp.ndarray:
     """x (M, K) @ dequant(packed (K//2, N), absmax (K//block, N))."""
     M, K = x.shape
     N = packed.shape[1]
@@ -123,22 +135,24 @@ def nf4_matmul_pallas(x: jnp.ndarray, packed: jnp.ndarray,
     bm, bn = min(bm, M), min(bn, N)
     bk = min(bk, K)
     bk = max(block, (bk // block) * block)           # bk multiple of block
-    if M % bm or N % bn or K % bk or bk % 2:
+    if M % bm or N % bn or K % bk or block % 2:
         raise ValueError(f"shape ({M},{K},{N}) not tileable by "
                          f"({bm},{bk},{bn}) block={block}")
     grid = (M // bm, N // bn, K // bk)
+    nb, hb = bk // block, block // 2
+    x_spec = pl.BlockSpec((bm, bk // 2), lambda i, j, k: (i, k))
     return pl.pallas_call(
-        functools.partial(_nf4_kernel, compute_dtype=compute_dtype,
-                          block=block),
+        functools.partial(_nf4_kernel, compute_dtype=compute_dtype),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk // 2, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bk // block, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, 16), lambda i, j, k: (0, 0)),
+            x_spec,
+            x_spec,
+            pl.BlockSpec((nb, hb, bn), lambda i, j, k: (k, 0, j)),
+            pl.BlockSpec((nb, 1, bn), lambda i, j, k: (k, 0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), compute_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(x, packed, absmax, jnp.asarray(_NF4_LUT).reshape(1, 16))
+    )(x[:, 0::2], x[:, 1::2], packed.reshape(K // block, hb, N),
+      absmax.reshape(K // block, 1, N))

@@ -1,102 +1,89 @@
-"""Serving launcher.
+"""Serving launcher: one :class:`~repro.api.ExperimentSpec` from the
+command line.
 
-Modes:
-
-* default: run the continuous-batching engine on ``--arch`` (reduced
-  variant) with REAL execution and a chosen arrival pattern, printing
-  the phase-aware energy report — the production serve loop in
-  miniature.
-* ``--sim``: discrete-event simulation of the FULL config (no device
-  compute) — how the paper-scale serving studies run.
-* ``--dry``: lower+compile the full-size serve_step on the production
-  mesh (decode_32k shape).
+* default: serve ``--arch`` at its full config through the executed
+  backend (random weights from ``PRNGKey(0)``, quantized when ``--fmt``
+  is int8 or nf4) on JAX's default device, printing the phase-aware
+  report. On a TPU the run bills that chip's DeviceSpec.
+* ``--reduced``: the same path on ``cfg.reduced()``, for CPU runs.
+* ``--sim``: analytic simulation of the config (no device compute) on
+  the spec's paper workload — how the paper-scale serving studies run.
 
     PYTHONPATH=src python -m repro.launch.serve --arch stablelm-1.6b
-    PYTHONPATH=src python -m repro.launch.serve --arch minitron-8b --sim \
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.serve \\
+        --reduced --n 4
+    PYTHONPATH=src python -m repro.launch.serve --arch minitron-8b --sim \\
         --pattern fixed --interval-ms 20 --n 500
 """
 from __future__ import annotations
 
 import argparse
 
-import numpy as np
+from repro.api import ExperimentSpec, RunResult
+
+#: (prompt_range, output_range, buf_len) of the executed modes: narrow
+#: prompts keep the padded prefill shapes few
+EXECUTED_SHAPES = {
+    "full": ((240, 256), (16, 64), 1024),
+    "reduced": ((8, 24), (4, 12), 64),
+}
 
 
-def main() -> None:
+def _arrival(pattern: str, dt: float):
+    return {
+        "burst": ("all_at_once", {}),
+        "fixed": ("fixed", {"interval_s": dt}),
+        "random": ("uniform", {"low_s": 0.0, "high_s": 2 * dt}),
+        "poisson": ("poisson", {"rate_per_s": 1.0 / max(dt, 1e-6)}),
+    }[pattern]
+
+
+def _parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-1.6b")
+    ap.add_argument("--fmt", default="bfloat16")
     ap.add_argument("--n", type=int, default=24)
     ap.add_argument("--pattern", default="burst",
                     choices=["burst", "fixed", "random", "poisson"])
     ap.add_argument("--interval-ms", type=float, default=20.0)
     ap.add_argument("--max-batch", type=int, default=8)
-    ap.add_argument("--fmt", default="bfloat16")
     ap.add_argument("--mode", default="continuous",
                     choices=["continuous", "sequential"])
-    ap.add_argument("--kv-quant", action="store_true")
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve cfg.reduced() (CPU-sized)")
     ap.add_argument("--sim", action="store_true",
-                    help="energy/latency simulation of the FULL config")
-    ap.add_argument("--dry", action="store_true")
-    args = ap.parse_args()
+                    help="analytic simulation, no device compute")
+    return ap
 
-    if args.dry:
-        from repro.launch import dryrun
-        dryrun.run_one(args.arch, "decode_32k", multi_pod=False,
-                       fmt="bfloat16", force=True, save=False,
-                       kv_quant=args.kv_quant)
-        print("dry serve_step lower+compile OK")
-        return
 
-    from repro.configs import get_config
-    from repro.serving import (ServeEngine, Request, fixed_arrivals,
-                               uniform_random_arrivals, poisson_arrivals)
-    from repro.training.data import RequestDistribution
-
-    dt = args.interval_ms / 1e3
-    arrivals = {
-        "burst": lambda n: [0.0] * n,
-        "fixed": lambda n: fixed_arrivals(n, dt),
-        "random": lambda n: uniform_random_arrivals(n, 0.0, 2 * dt),
-        "poisson": lambda n: poisson_arrivals(n, 1.0 / max(dt, 1e-6)),
-    }[args.pattern](args.n)
-
+def build_spec(argv=None) -> ExperimentSpec:
+    args = _parser().parse_args(argv)
+    arrival, params = _arrival(args.pattern, args.interval_ms / 1e3)
+    kw = dict(model=args.arch, fmt=args.fmt, reduced=args.reduced,
+              mode=args.mode, max_batch=args.max_batch, n_requests=args.n,
+              arrival=arrival, arrival_params=params)
     if args.sim:
-        cfg = get_config(args.arch)
-        dist = RequestDistribution(seed=0)
-        reqs = []
-        for i in range(args.n):
-            s = dist.sample()
-            reqs.append(Request(req_id=i, prompt=None,
-                                prompt_len=s.prompt_len,
-                                max_new_tokens=s.output_len,
-                                arrival_time=arrivals[i]))
-        eng = ServeEngine(cfg, fmt=args.fmt, mode=args.mode,
-                          max_batch=args.max_batch)
-        rep = eng.run(reqs)
-    else:
-        import jax
-        from repro.models import build_model
-        cfg = get_config(args.arch).reduced()
-        model = build_model(cfg, fmt="float32",
-                            kv_quant=args.kv_quant)
-        params = model.init(jax.random.PRNGKey(0))
-        rng = np.random.default_rng(0)
-        reqs = []
-        for i in range(args.n):
-            plen = int(rng.integers(8, 24))
-            reqs.append(Request(
-                req_id=i,
-                prompt=rng.integers(0, cfg.vocab_size,
-                                    plen).astype(np.int32),
-                prompt_len=plen,
-                max_new_tokens=int(rng.integers(4, 12)),
-                arrival_time=arrivals[i]))
-        eng = ServeEngine(cfg, fmt=args.fmt, mode=args.mode,
-                          max_batch=args.max_batch, execute=True,
-                          model=model, params=params, buf_len=64)
-        rep = eng.run(reqs)
-    for k, v in rep.summary().items():
+        return ExperimentSpec(backend="analytic", **kw)
+    import jax
+    from repro.core.hardware import device_for_kind
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        kw["device"] = device_for_kind(dev.device_kind).name
+    prompts, outputs, buf_len = EXECUTED_SHAPES[
+        "reduced" if args.reduced else "full"]
+    return ExperimentSpec(backend="executed", prompt_range=prompts,
+                          output_range=outputs, buf_len=buf_len, **kw)
+
+
+def main(argv=None) -> RunResult:
+    spec = build_spec(argv)
+    if spec.backend == "executed":
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
+    res = spec.run()
+    for k, v in res.report.summary().items():
         print(f"{k:22s} {v:.6g}")
+    return res
 
 
 if __name__ == "__main__":

@@ -38,9 +38,9 @@ class Model:
     cfg: ModelConfig
     policy: PrecisionPolicy
     # sliding-window override (the long_500k SWA-variant for full-attention
-    # archs — DESIGN.md §4). None = use cfg.sliding_window.
+    # archs). None = use cfg.sliding_window.
     window_override: Optional[int] = None
-    # int8 KV cache (EXPERIMENTS.md §Perf H3): absmax-per-(token, head)
+    # int8 KV cache: absmax-per-(token, head)
     # quantized K/V halves the decode phase's dominant HBM term. Applies
     # to the transformer-family caches (dense/moe/vlm/audio).
     kv_quant: bool = False

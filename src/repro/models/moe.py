@@ -1,6 +1,6 @@
 """Top-k Mixture-of-Experts FFN with capacity-based dispatch.
 
-TPU-native design (DESIGN.md §5): tokens are sorted by expert id and
+TPU-native design: tokens are sorted by expert id and
 scattered into a dense (experts, capacity, d_model) buffer, experts run as
 one batched einsum, and results gather back. Under pjit with experts
 sharded on the ``model`` axis this induces the canonical all-to-all;
@@ -18,27 +18,15 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:                                    # jax >= 0.6: public top-level API
-    from jax import shard_map
-except ImportError:                     # older jax: experimental path, with
-    import functools                    # check_rep instead of check_vma
-
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    @functools.wraps(_shard_map_exp)
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True, **kw):
-        return _shard_map_exp(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma,
-                              **kw)
 
 from repro.core.precision import PrecisionPolicy
 from repro.quant.apply import linear_apply
 
 # Expert-parallel context: when a production mesh is active (set by the
 # launcher around tracing), moe_ffn routes through the shard_map
-# expert-parallel implementation below (EXPERIMENTS.md §Perf H1).
+# expert-parallel implementation below.
 _EP_CTX: contextvars.ContextVar = contextvars.ContextVar(
     "moe_expert_parallel", default=None)
 
@@ -152,7 +140,7 @@ def _expert_dense(w: Any, x: jnp.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# expert-parallel shard_map path (EXPERIMENTS.md §Perf H1/H2)
+# expert-parallel shard_map path
 #
 # The sort/scatter dispatch above is correct but not SPMD-partitionable
 # across (tokens x experts): XLA falls back to replicating the dense

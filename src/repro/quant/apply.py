@@ -7,9 +7,10 @@ dispatches on the parameter *representation*:
 * Int8Weight   -> LLM.int8-style dequant matmul (+outlier matmul),
 * NF4Weight    -> NF4 on-the-fly dequant matmul.
 
-When ``policy.use_pallas_kernels`` is set (tests/benchmarks on small
-shapes), quantized matmuls run through the Pallas ``quant_matmul`` kernel
-in interpret mode instead of the pure-jnp reference path.
+When ``policy.use_pallas_kernels`` is set (an executed run on a TPU
+sets it, see :meth:`repro.api.ExperimentSpec.build_engine`), quantized
+matmuls run through the compiled Pallas ``quant_matmul`` kernel instead
+of the pure-jnp reference path.
 """
 from __future__ import annotations
 
@@ -50,8 +51,8 @@ def linear_apply(w: Any, x: jnp.ndarray,
     TPU the MXU still accumulates partial products in f32 internally,
     but row-parallel (TP) partial sums then cross shards in bf16 —
     halving every tensor-parallel all-reduce (fwd and cotangent). This
-    is the Megatron-style bf16-reduction tradeoff; see EXPERIMENTS.md
-    §Perf H1 iteration 3. f32 policies keep f32 end-to-end.
+    is the Megatron-style bf16-reduction tradeoff. f32 policies keep
+    f32 end-to-end.
     """
     cd = policy.compute_dtype
     if isinstance(w, Int8Weight):

@@ -64,7 +64,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core import workload as W
 from repro.core.energy import EnergyModel, EnergyReport
-from repro.core.hardware import DeviceSpec, H100_SXM
+from repro.core.hardware import DeviceSpec, H100_SXM, check_executed_device
 from repro.core.precision import PrecisionPolicy, make_policy
 from repro.batching.policy import SlotCountPolicy
 
@@ -444,6 +444,14 @@ class ExecutedBackend(AnalyticBackend):
         super().__init__(cfg, **analytic_kw)
         assert model is not None and params is not None
         import jax
+        dev = jax.devices()[0]
+        check_executed_device(self.device, dev.platform, dev.device_kind)
+        if (dev.platform == "tpu" and model.policy.is_quantized
+                and not model.policy.use_pallas_kernels):
+            raise ValueError(
+                f"fmt={model.policy.fmt!r} on a TPU must run the compiled "
+                "quant_matmul kernel; build the model with "
+                "use_pallas_kernels=True")
         self.model = model
         self.params = params
         self.max_batch = max_batch

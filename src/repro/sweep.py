@@ -266,8 +266,10 @@ def _cache_path(spec: ExperimentSpec, cache_dir: Optional[str]) -> str:
 def _cache_enabled(spec: ExperimentSpec, cache: bool) -> bool:
     """Replay-backend specs are never memoized: the hash sees only the
     trace-file *path*, so a re-recorded trace would silently serve
-    stale results."""
-    return cache and spec.backend != "replay"
+    stale results. Executed specs are never memoized either: the hash
+    does not see the device, so a record made on one machine would
+    answer for a run on another."""
+    return cache and spec.effective_backend() == "analytic"
 
 
 def _cache_try(spec: ExperimentSpec, cache: bool,
@@ -306,7 +308,7 @@ def run_spec(spec: ExperimentSpec, *, cache: bool = True,
     axis change re-runs and identical specs are served from disk.
     Cache writes are atomic (temp file + ``os.replace``), so parallel
     workers and interrupted sweeps never corrupt an entry.
-    Replay-backend specs are never memoized (see
+    Replay- and executed-backend specs are never memoized (see
     :func:`_cache_enabled`)."""
     cache = _cache_enabled(spec, cache)
     path = _cache_path(spec, cache_dir)
@@ -351,6 +353,8 @@ def sweep(base: ExperimentSpec,
     ``workers > 1`` runs the cache-miss points in a process pool
     (cache hits are still served in-process; memoization stays
     spec-hash keyed and atomic, so concurrent writers are safe).
+    Executed specs always run in this process: an accelerator belongs
+    to one process, and a spawned child could not open it.
     Results are returned in the deterministic grid-label order either
     way. Defaults to the ``REPRO_SWEEP_WORKERS`` environment variable
     (how ``benchmarks/run.py --workers`` reaches every suite), else 1.
@@ -365,6 +369,9 @@ def sweep(base: ExperimentSpec,
             hit = _cache_try(spec, cache, cache_dir)
             if hit is not None:
                 runs[idx] = (hit, True)
+            elif spec.effective_backend() == "executed":
+                runs[idx] = run_spec(spec, cache=cache,
+                                     cache_dir=cache_dir)
             else:
                 misses.append(idx)
         if misses:

@@ -34,7 +34,7 @@ class TestQuantMatmul:
         w = _rand((k, n), 1, scale=0.05)
         q = quantize_int8(w)
         out = int8_matmul_pallas(x, q.codes, q.scale, bm=bm, bn=bn, bk=bk,
-                                 compute_dtype=jnp.float32)
+                                 compute_dtype=jnp.float32, interpret=True)
         ref = int8_matmul_ref(x, q.codes, q.scale)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-5, atol=1e-5)
@@ -46,7 +46,7 @@ class TestQuantMatmul:
         w = _rand((256, 128), 1, scale=0.05)
         q = quantize_int8(w)
         out = int8_matmul_pallas(x, q.codes, q.scale, bm=32, bn=128,
-                                 bk=128, compute_dtype=dtype)
+                                 bk=128, compute_dtype=dtype, interpret=True)
         ref = int8_matmul_ref(x, q.codes, q.scale)
         rel = np.abs(np.asarray(out, np.float32) - np.asarray(ref)).max() \
             / (np.abs(np.asarray(ref)).max() + 1e-9)
@@ -59,7 +59,8 @@ class TestQuantMatmul:
         w = _rand((k, n), 1, scale=0.05)
         q = quantize_nf4(w, block)
         out = nf4_matmul_pallas(x, q.packed, q.absmax, bm=m, bn=n,
-                                bk=min(128, k), compute_dtype=jnp.float32)
+                                bk=min(128, k), compute_dtype=jnp.float32,
+                                interpret=True)
         ref = nf4_matmul_ref(x, q.packed, q.absmax)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-5, atol=1e-5)
@@ -70,7 +71,8 @@ class TestQuantMatmul:
         w[3] *= 50                                   # force an outlier row
         w = jnp.asarray(w)
         q = quantize_int8(w, outlier_fraction=0.02)
-        out = qops.int8_matmul_kernel(x, q, compute_dtype=jnp.float32)
+        out = qops.int8_matmul_kernel(x, q, compute_dtype=jnp.float32,
+                                       interpret=True)
         ref = jnp.einsum("bsk,kn->bsn", x, w)
         rel = float(jnp.max(jnp.abs(out - ref))
                     / (jnp.max(jnp.abs(ref)) + 1e-9))
@@ -81,10 +83,40 @@ class TestQuantMatmul:
         x = _rand((2, 8, 128), 0, scale=1.0)
         w = _rand((128, 64), 1, scale=0.05)
         q = quantize_nf4(w, 64)
-        out = qops.nf4_matmul_kernel(x, q, compute_dtype=jnp.float32)
+        out = qops.nf4_matmul_kernel(x, q, compute_dtype=jnp.float32,
+                                      interpret=True)
         ref = nf4_matmul_ref(x.reshape(-1, 128), q.packed, q.absmax)
         np.testing.assert_allclose(np.asarray(out).reshape(-1, 64),
                                    np.asarray(ref), rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("m", [3, 8, 256, 300, 520, 4160])
+    def test_row_blocks_pad_little(self, m):
+        bm, _, _, mp = qops._pick_blocks(m, 2048, 5632)
+        assert bm <= qops.ROW_BLOCK and mp % bm == 0 and mp >= m
+        if m <= qops.ROW_BLOCK:
+            assert mp == bm and mp - m < 8
+        else:
+            assert bm % 16 == 0 and mp - m < 16 * (mp // bm)
+
+    @pytest.mark.parametrize("fmt", ["int8", "nf4"])
+    def test_ops_wrapper_split_rows(self, fmt):
+        """Rows past one block split into padded blocks; the padding is
+        sliced off."""
+        x = _rand((300, 128), 0, scale=1.0)
+        w = _rand((128, 64), 1, scale=0.05)
+        if fmt == "int8":
+            q = quantize_int8(w)
+            out = qops.int8_matmul_kernel(x, q, compute_dtype=jnp.float32,
+                                          interpret=True)
+            ref = int8_matmul_ref(x, q.codes, q.scale)
+        else:
+            q = quantize_nf4(w, 64)
+            out = qops.nf4_matmul_kernel(x, q, compute_dtype=jnp.float32,
+                                         interpret=True)
+            ref = nf4_matmul_ref(x, q.packed, q.absmax)
+        assert out.shape == (300, 64)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
 
 
 class TestFlashAttention:
@@ -97,7 +129,7 @@ class TestFlashAttention:
         k = _rand((B, S, Kv, d), 1, scale=1.0)
         v = _rand((B, S, Kv, d), 2, scale=1.0)
         out = flash_attention_pallas(q, k, v, causal=causal, bq=bq,
-                                     bkv=bkv)
+                                     bkv=bkv, interpret=True)
         ref = attention_ref(q, k, v, causal=causal)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
@@ -109,7 +141,7 @@ class TestFlashAttention:
         k = _rand((B, S, Kv, d), 1, scale=1.0)
         v = _rand((B, S, Kv, d), 2, scale=1.0)
         out = flash_attention_pallas(q, k, v, causal=True, window=window,
-                                     bq=64, bkv=64)
+                                     bq=64, bkv=64, interpret=True)
         ref = attention_ref(q, k, v, causal=True, window=window)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
@@ -120,7 +152,8 @@ class TestFlashAttention:
         q = _rand((B, S, H, d), 0, scale=1.0)
         k = _rand((B, S, Kv, d), 1, scale=1.0)
         v = _rand((B, S, Kv, d), 2, scale=1.0)
-        out = flash_attention_pallas(q, k, v, bq=64, bkv=64)
+        out = flash_attention_pallas(q, k, v, bq=64, bkv=64,
+                                     interpret=True)
         ref = attention_ref(q, k, v)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
@@ -130,7 +163,8 @@ class TestFlashAttention:
         q = _rand((B, S, H, d), 0, jnp.bfloat16, 1.0)
         k = _rand((B, S, Kv, d), 1, jnp.bfloat16, 1.0)
         v = _rand((B, S, Kv, d), 2, jnp.bfloat16, 1.0)
-        out = flash_attention_pallas(q, k, v, bq=64, bkv=64)
+        out = flash_attention_pallas(q, k, v, bq=64, bkv=64,
+                                     interpret=True)
         ref = attention_ref(q.astype(jnp.float32), k.astype(jnp.float32),
                             v.astype(jnp.float32))
         assert float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref))) < 0.05
@@ -148,7 +182,7 @@ class TestPagedAttention:
         q = _rand((B, H, d), 5, scale=1.0)
         pt = jnp.array([[0, 1, 2], [3, 4, -1]], jnp.int32)
         sl = jnp.array([2 * page + 3, page + 1], jnp.int32)
-        out = paged_attention_pallas(q, kp, vp, pt, sl)
+        out = paged_attention_pallas(q, kp, vp, pt, sl, interpret=True)
         ref = paged_attention_ref(q, kp, vp, pt, sl)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
@@ -161,7 +195,7 @@ class TestPagedAttention:
         q = _rand((B, H, d), 9, scale=1.0)
         pt = jnp.array([[2, 0], [5, -1]], jnp.int32)
         sl = jnp.array([50, 20], jnp.int32)
-        out = paged_attention_pallas(q, kp, vp, pt, sl)
+        out = paged_attention_pallas(q, kp, vp, pt, sl, interpret=True)
         # build contiguous caches and use the flash oracle (q len 1)
         for b in range(B):
             pages = [p for p in np.asarray(pt[b]) if p >= 0]
@@ -179,7 +213,7 @@ class TestPagedAttention:
         q = _rand((B, H, d), 3, scale=1.0)
         pt = jnp.array([[1]], jnp.int32)
         sl = jnp.array([7], jnp.int32)
-        out = paged_attention_pallas(q, kp, vp, pt, sl)
+        out = paged_attention_pallas(q, kp, vp, pt, sl, interpret=True)
         ref = paged_attention_ref(q, kp, vp, pt, sl)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
