@@ -12,7 +12,6 @@
     backends / DVFS      -> benchmarks.backend
     §6 macro estimate    -> benchmarks.macro
     simulator perf (ours)-> benchmarks.simperf
-    roofline (ours, §g)  -> benchmarks.roofline_report
     CPU wall-time micro  -> benchmarks.microbench
 
 The paper-figure suites are declarative sweeps over
@@ -67,8 +66,8 @@ def _row_record(suite: str, row) -> dict:
 def _benches():
     from benchmarks import (backend, batching, cluster, control, fleet,
                             formation, macro, microbench, precision,
-                            resilience, roofline_report, scheduler,
-                            serving, simperf, workflows)
+                            resilience, scheduler, serving, simperf,
+                            workflows)
     return [("precision", precision),
             ("batching", batching),
             ("serving", serving),
@@ -82,7 +81,6 @@ def _benches():
             ("backend", backend),
             ("macro", macro),
             ("simperf", simperf),
-            ("roofline", roofline_report),
             ("microbench", microbench)]
 
 

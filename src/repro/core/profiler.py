@@ -12,7 +12,6 @@ decode as the remainder — mirrored here by construction.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Optional
 
 from repro.configs.base import ModelConfig
@@ -97,14 +96,3 @@ class PhaseProfiler:
                                batch=batch, prompt_len=prompt_len,
                                new_tokens=new_tokens)
 
-
-class WallClock:
-    """Tiny wall-clock context for CPU-relative latency comparisons."""
-
-    def __enter__(self):
-        self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self.elapsed = time.perf_counter() - self.t0
-        return False

@@ -56,6 +56,7 @@ import abc
 import dataclasses
 import json
 import math
+import time
 from typing import (TYPE_CHECKING, Any, Dict, List, Mapping, Optional,
                     Tuple)
 
@@ -67,6 +68,7 @@ from repro.core.energy import EnergyModel, EnergyReport
 from repro.core.hardware import DeviceSpec, H100_SXM, check_executed_device
 from repro.core.precision import PrecisionPolicy, make_policy
 from repro.batching.policy import SlotCountPolicy
+from repro.serving import spans
 
 if TYPE_CHECKING:   # event-horizon boundaries (duck-typed at runtime)
     from repro.serving.scheduler import HorizonStop
@@ -469,7 +471,8 @@ class ExecutedBackend(AnalyticBackend):
 
     # -- protocol -------------------------------------------------------
     def prefill(self, batch: PrefillBatch) -> PhaseResult:
-        res = super().prefill(batch)
+        with spans.span(spans.COST, phase="prefill"):
+            res = super().prefill(batch)
         if any(slot is not None for slot, _ in batch.picks):
             if batch.chunk_len:
                 # chunk costing is analytic (above); the genuine model
@@ -483,7 +486,8 @@ class ExecutedBackend(AnalyticBackend):
         return res
 
     def decode_step(self, batch: DecodeBatch) -> PhaseResult:
-        res = super().decode_step(batch)
+        with spans.span(spans.COST, phase="decode"):
+            res = super().decode_step(batch)
         self._execute_decode(batch)
         return res
 
@@ -535,22 +539,33 @@ class ExecutedBackend(AnalyticBackend):
         for j, (_, r) in enumerate(picks):
             toks[j, :r.prompt_len] = r.prompt[:exec_pad]
             lens[j] = r.prompt_len
-        logits, pcache = self._jit_prefill(
-            self.params, {"tokens": jnp.asarray(toks)}, jnp.asarray(lens))
-        first = np.asarray(jnp.argmax(logits, -1))
+        t_launch = time.perf_counter()
+        for _, r in picks:
+            r.t_launch_host = t_launch
+        with spans.span(spans.LAUNCH, program="prefill", rows=len(picks)):
+            logits, pcache = self._jit_prefill(
+                self.params, {"tokens": jnp.asarray(toks)},
+                jnp.asarray(lens))
+            ids = jnp.argmax(logits, -1)
+        with spans.span(spans.SYNC, program="prefill"):
+            first = np.asarray(ids)
         for j, (slot, r) in enumerate(picks):
             r.generated = [int(first[j])]
-            self.cache = insert_cache_slot(self.cache, pcache, j, slot)
-            self.slot_tokens = self.slot_tokens.at[slot, 0].set(
-                int(first[j]))
+            with spans.span(spans.INSERT, slot=slot, req=r.req_id):
+                self.cache = insert_cache_slot(self.cache, pcache, j, slot)
+                self.slot_tokens = self.slot_tokens.at[slot, 0].set(
+                    int(first[j]))
 
     def _execute_decode(self, batch: DecodeBatch) -> None:
         import jax.numpy as jnp
-        logits, self.cache = self._jit_decode(self.params,
-                                              self.slot_tokens, self.cache)
-        nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-        self.slot_tokens = nxt[:, None]
-        arr = np.asarray(nxt)
+        with spans.span(spans.LAUNCH, program="decode",
+                        rows=self.max_batch):
+            logits, self.cache = self._jit_decode(
+                self.params, self.slot_tokens, self.cache)
+            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+            self.slot_tokens = nxt[:, None]
+        with spans.span(spans.SYNC, program="decode"):
+            arr = np.asarray(nxt)
         for slot, req in zip(batch.slots, batch.requests):
             req.generated.append(int(arr[slot]))
 

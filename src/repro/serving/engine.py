@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import time
 from bisect import bisect_right as _bisect_right
 from typing import Dict, List, Optional, Sequence
 
@@ -47,7 +48,7 @@ from repro.serving.backend import (AnalyticBackend, DecodeBatch,
 from repro.serving.requests import Request, RequestStatus
 from repro.serving.scheduler import (HorizonStop, Scheduler,
                                      apply_schedule)
-from repro.serving import slo
+from repro.serving import slo, spans
 from repro.serving.trace import PowerTrace
 
 
@@ -924,6 +925,8 @@ class ServeEngine:
         return float(self.batch_policy.outstanding_tokens(self.batcher))
 
     def stream_submit(self, req: Request) -> None:
+        if self.execute:
+            req.t_submit_host = time.perf_counter()
         self._stream.submitted.append(req)
         self.batcher.admit(req)
 
@@ -957,7 +960,9 @@ class ServeEngine:
         next shaped release / cluster sync point). Returns the phase
         latency (0.0 if there was nothing to do)."""
         s, b = self._stream, self.batcher
-        plan = self.batch_policy.schedule_prefill(b, s.now)
+        with spans.span(spans.SCHEDULE, waiting=b.n_waiting, live=b.n_live,
+                        free=b.free_count):
+            plan = self.batch_policy.schedule_prefill(b, s.now)
         if plan is not None and plan.picks:
             if plan.adopt:
                 # prefill already ran on another replica (disaggregated
@@ -969,86 +974,97 @@ class ServeEngine:
                 self._finish_ready(b, s.done, s.now)
                 return 0.0
             picks = plan.picks
-            res = self.backend.prefill(PrefillBatch(
-                picks=picks, pad_len=plan.pad_len, stack=self.stack,
-                chunk_start=plan.chunk_start, chunk_len=plan.chunk_len))
-            self._record("prefill", s.now, s.now + res.latency_s,
-                         res.energy_j, float(len(picks)))
-            self._last_phase_start = s.now
-            s.now += res.latency_s
-            s.busy_t += res.latency_s
-            s.busy_e += res.energy_j
-            s.n_prefills += 1
-            if plan.is_chunk:
-                slot, r = picks[0]
-                if r.t_prefill_start < 0:
-                    # first compute phase — for a resumed workflow child
-                    # chunk_start > 0 here: those tokens were never
-                    # recomputed, their KV was forked from the parent
+            with spans.span(spans.PREFILL, rows=len(picks),
+                            pad=plan.pad_len,
+                            reqs=" ".join(str(r.req_id) for _, r in picks)):
+                res = self.backend.prefill(PrefillBatch(
+                    picks=picks, pad_len=plan.pad_len, stack=self.stack,
+                    chunk_start=plan.chunk_start, chunk_len=plan.chunk_len))
+                self._record("prefill", s.now, s.now + res.latency_s,
+                             res.energy_j, float(len(picks)))
+                self._last_phase_start = s.now
+                s.now += res.latency_s
+                s.busy_t += res.latency_s
+                s.busy_e += res.energy_j
+                s.n_prefills += 1
+                if plan.is_chunk:
+                    slot, r = picks[0]
+                    if r.t_prefill_start < 0:
+                        # first compute phase — for a resumed workflow
+                        # child chunk_start > 0 here: those tokens were
+                        # never recomputed, their KV was forked from the
+                        # parent
+                        r.status = RequestStatus.RUNNING
+                        r.t_prefill_start = s.now - res.latency_s
+                        if plan.chunk_start:
+                            s.prefix_reused += plan.chunk_start
+                    r.energy_j += res.energy_j
+                    s.prefill_chunks += 1
+                    s.prefill_computed += plan.chunk_len
+                    s.prefill_effective += plan.chunk_len
+                    if b.note_chunk(slot, plan.chunk_len):
+                        r.t_first_token = s.now
+                        r.tokens_generated = 1
+                        if self.pool == "prefill":
+                            self._relay([(slot, r)])
+                        else:
+                            self._finish_ready(b, s.done, s.now)
+                    return res.latency_s
+                for slot, r in picks:
                     r.status = RequestStatus.RUNNING
                     r.t_prefill_start = s.now - res.latency_s
-                    if plan.chunk_start:
-                        s.prefix_reused += plan.chunk_start
-                r.energy_j += res.energy_j
-                s.prefill_chunks += 1
-                s.prefill_computed += plan.chunk_len
-                s.prefill_effective += plan.chunk_len
-                if b.note_chunk(slot, plan.chunk_len):
                     r.t_first_token = s.now
                     r.tokens_generated = 1
-                    if self.pool == "prefill":
-                        self._relay([(slot, r)])
-                    else:
-                        self._finish_ready(b, s.done, s.now)
+                    r.energy_j += res.energy_j / len(picks)
+                    b.complete_prefill(slot)
+                s.prefill_computed += len(picks) * plan.pad_len
+                s.prefill_effective += sum(r.prompt_len for _, r in picks)
+                if self.pool == "prefill":
+                    self._relay(picks)
+                else:
+                    self._finish_ready(b, s.done, s.now)
                 return res.latency_s
-            for slot, r in picks:
-                r.status = RequestStatus.RUNNING
-                r.t_prefill_start = s.now - res.latency_s
-                r.t_first_token = s.now
-                r.tokens_generated = 1
-                r.energy_j += res.energy_j / len(picks)
-                b.complete_prefill(slot)
-            s.prefill_computed += len(picks) * plan.pad_len
-            s.prefill_effective += sum(r.prompt_len for _, r in picks)
-            if self.pool == "prefill":
-                self._relay(picks)
-            else:
-                self._finish_ready(b, s.done, s.now)
-            return res.latency_s
         live = b.decode_ready_slots()
         if live:
             reqs = [b.slots[i].request for i in live]
-            k, completes = (self._decode_horizon(reqs)
-                            if self.macro_step else (1, True))
-            cap = self.batch_policy.decode_horizon_cap(b)
-            if cap is not None and k > cap:
-                k, completes = cap, False
-            if k > 1:
-                lat = self._decode_macro(live, reqs, k, completes,
-                                         stop)
+            with spans.span(spans.SCHEDULE, waiting=b.n_waiting,
+                            live=b.n_live, free=b.free_count):
+                k, completes = (self._decode_horizon(reqs)
+                                if self.macro_step else (1, True))
+                cap = self.batch_policy.decode_horizon_cap(b)
+                if cap is not None and k > cap:
+                    k, completes = cap, False
+            with spans.span(spans.DECODE, lanes=len(live),
+                            steps=k) as span:
+                if k > 1:
+                    n0 = s.n_decode
+                    lat = self._decode_macro(live, reqs, k, completes,
+                                             stop)
+                    span.set_metadata(ran=s.n_decode - n0)
+                    self.batch_policy.note_decode()
+                    return lat
+                res = self.backend.decode_step(DecodeBatch(
+                    slots=live, requests=reqs,
+                    cache_lens=[r.prompt_len + r.tokens_generated
+                                for r in reqs],
+                    stack=self.stack))
+                self._record("decode", s.now, s.now + res.latency_s,
+                             res.energy_j, float(len(live)))
+                self._last_phase_start = s.now
+                s.now += res.latency_s
+                s.busy_t += res.latency_s
+                s.busy_e += res.energy_j
+                s.decode_time += res.latency_s
+                s.batch_time += res.latency_s * len(live)
+                s.n_decode += 1
+                b.step_decode_bookkeeping()
+                for r in reqs:
+                    r.tokens_generated += 1
+                    r.energy_j += res.energy_j / len(live)
+                span.set_metadata(ran=1)
                 self.batch_policy.note_decode()
-                return lat
-            res = self.backend.decode_step(DecodeBatch(
-                slots=live, requests=reqs,
-                cache_lens=[r.prompt_len + r.tokens_generated
-                            for r in reqs],
-                stack=self.stack))
-            self._record("decode", s.now, s.now + res.latency_s,
-                         res.energy_j, float(len(live)))
-            self._last_phase_start = s.now
-            s.now += res.latency_s
-            s.busy_t += res.latency_s
-            s.busy_e += res.energy_j
-            s.decode_time += res.latency_s
-            s.batch_time += res.latency_s * len(live)
-            s.n_decode += 1
-            b.step_decode_bookkeeping()
-            for r in reqs:
-                r.tokens_generated += 1
-                r.energy_j += res.energy_j / len(live)
-            self.batch_policy.note_decode()
-            self._finish_ready(b, s.done, s.now)
-            return res.latency_s
+                self._finish_ready(b, s.done, s.now)
+                return res.latency_s
         return 0.0
 
     def _relay(self, picks) -> None:

@@ -42,6 +42,11 @@ class Request:
     t_prefill_start: float = -1.0
     t_first_token: float = -1.0
     t_done: float = -1.0
+    # host clock (time.perf_counter() seconds) of the executed path:
+    # when the engine took the request, and when its prefill program
+    # was launched; -1.0 in the simulator
+    t_submit_host: float = -1.0
+    t_launch_host: float = -1.0
     prefilled_tokens: int = 0           # prompt tokens whose KV exists
     tokens_generated: int = 0
     generated: list = dataclasses.field(default_factory=list)
