@@ -21,9 +21,9 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core.precision import PrecisionPolicy
 from repro.models import moe as moe_mod
-from repro.models.layers import (apply_rope, attention, cache_write_decode,
-                                 chunked_attention, decode_attention_mask,
-                                 gated_mlp, rms_norm)
+from repro.models.layers import (apply_rope, attention, cache_write_tokens,
+                                 chunked_attention, decode_attention,
+                                 decode_attention_mask, gated_mlp, rms_norm)
 from repro.quant.apply import linear_apply, linear_init
 
 CHUNKED_ATTN_THRESHOLD = 2048
@@ -243,6 +243,10 @@ def decoder_decode_step(stack: Dict[str, Any], x: jnp.ndarray,
     """One-token decode. x: (B, 1, D). cache: see layers.init_kv_cache
     (per-row pos (B,) / slot_pos (B, W)).
 
+    Each layer attends over its cache slice as it was before this step
+    (the slot being overwritten masked out) plus the token's own K/V;
+    the scan emits only the new K/V, written into the cache after it
+    by :func:`cache_write_tokens` (in place when the cache is donated).
     Returns (hidden (B,1,D), new_cache).
     """
     pos = cache["pos"]                                         # (B,)
@@ -250,52 +254,44 @@ def decoder_decode_step(stack: Dict[str, Any], x: jnp.ndarray,
     W = cache["k"].shape[2]
     B = x.shape[0]
     slot = jnp.mod(pos, W)
-    new_slot_pos = slot_pos.at[jnp.arange(B), slot].set(pos)
-    allow = decode_attention_mask(new_slot_pos, pos, window)   # (B, W)
+    allow = (decode_attention_mask(slot_pos, pos, window)
+             & (jnp.arange(W)[None, :] != slot[:, None]))      # (B, W)
     has_cross = enc_kv is not None
     quant = "k_scale" in cache                                 # int8 KV
-    rows = jnp.arange(B)
+    keys = ("k", "v", "k_scale", "v_scale") if quant else ("k", "v")
+    adt = policy.activation_dtype
 
-    def layer(carry, inp):
-        x = carry
-        if has_cross:
-            (lp, ck, cv, ek, ev), scales = inp[:5], inp[5:]
-        else:
-            (lp, ck, cv), scales = inp[:3], inp[3:]
+    def layer(x, inp):
+        lp, kv, enc = inp
         xn = rms_norm(x, lp["attn_norm"])
         q, k, v = _project_qkv(lp["attn"], xn, cfg, policy)
         pos1 = pos[:, None]                                    # (B, 1)
         q = apply_rope(q, pos1, cfg.rope_theta)
         k = apply_rope(k, pos1, cfg.rope_theta)
         if quant:
-            ks, vs = scales
-            kq, ksc = quantize_kv(k)
-            vq, vsc = quantize_kv(v)
-            ck, cv = cache_write_decode(ck, cv, kq, vq, pos)
-            ks = ks.at[rows, slot].set(ksc[:, 0])
-            vs = vs.at[rows, slot].set(vsc[:, 0])
-            kf = dequantize_kv(ck, ks, policy.activation_dtype)
-            vf = dequantize_kv(cv, vs, policy.activation_dtype)
-            new_scales = (ks, vs)
+            ck, cv, ks, vs = kv
+            (kq, ksc), (vq, vsc) = quantize_kv(k), quantize_kv(v)
+            kf, vf = dequantize_kv(ck, ks, adt), dequantize_kv(cv, vs, adt)
+            k_tok = dequantize_kv(kq, ksc, adt)
+            v_tok = dequantize_kv(vq, vsc, adt)
+            new = (kq, vq, ksc, vsc)
         else:
-            ck, cv = cache_write_decode(ck, cv, k, v, pos)
-            kf, vf = ck, cv
-            new_scales = ()
-        mask = allow[:, None, :]                               # (B, 1, W)
-        o = attention(q, kf, vf, mask=mask)
+            kf, vf = kv
+            k_tok, v_tok = k.astype(kf.dtype), v.astype(vf.dtype)
+            new = (k_tok, v_tok)
+        o = decode_attention(q, kf, vf, k_tok, v_tok, allow)
         x = x + linear_apply(lp["attn"]["wo"],
                              o.reshape(B, 1, -1), policy)
         if has_cross:
-            x = cross_attn_block(lp, x, ek, ev, cfg, policy)
+            x = cross_attn_block(lp, x, *enc, cfg, policy)
         x, _ = ffn_block(lp, x, cfg, policy)
-        return x, (ck, cv) + new_scales
+        return x, tuple(a[:, 0] for a in new)
 
-    base = ((stack, cache["k"], cache["v"], enc_kv[0], enc_kv[1])
-            if has_cross else (stack, cache["k"], cache["v"]))
-    xs = base + ((cache["k_scale"], cache["v_scale"]) if quant else ())
+    xs = (stack, tuple(cache[key] for key in keys),
+          enc_kv if has_cross else ())
     x, out = jax.lax.scan(layer, x, xs)
-    new_cache = dict(cache, k=out[0], v=out[1],
-                     slot_pos=new_slot_pos, pos=pos + 1)
-    if quant:
-        new_cache["k_scale"], new_cache["v_scale"] = out[2], out[3]
+    new_cache = dict(cache, slot_pos=slot_pos.at[jnp.arange(B), slot]
+                     .set(pos), pos=pos + 1)
+    for key, tok in zip(keys, out):
+        new_cache[key] = cache_write_tokens(cache[key], tok, slot)
     return x, new_cache

@@ -429,6 +429,14 @@ class AnalyticBackend(InferenceBackend):
 # ---------------------------------------------------------------------------
 # executed
 # ---------------------------------------------------------------------------
+def jit_decode_step(model):
+    """The served decode step, jitted with the cache (argument 2)
+    donated: the step writes its tokens into the cache in place, and the
+    caller rebinds its cache to the one returned."""
+    import jax
+    return jax.jit(model.decode_step, donate_argnums=(2,))
+
+
 class ExecutedBackend(AnalyticBackend):
     """Analytic costing + genuine JAX execution through the scheduler.
 
@@ -458,7 +466,7 @@ class ExecutedBackend(AnalyticBackend):
         self.params = params
         self.max_batch = max_batch
         self.buf_len = buf_len
-        self._jit_decode = jax.jit(model.decode_step)
+        self._jit_decode = jit_decode_step(model)
         self._jit_prefill = jax.jit(
             lambda p, b, l: model.prefill(p, b, buf_len=buf_len,
                                           lengths=l))

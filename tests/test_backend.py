@@ -285,6 +285,38 @@ class TestExecuted:
         assert all(len(r.generated) == r.max_new_tokens
                    for r in rep_b.requests)
 
+    # served by the decode step that wrote each token into a copy of the
+    # cache before attending over it (not donated); 8 + 12 tokens wrap
+    # the 16-slot ring
+    SERVED_IDS = [
+        [287, 411, 234, 300, 352, 353, 105, 234, 300, 264, 40, 352],
+        [297, 296, 297, 83, 296, 296, 296, 302, 31, 183, 161, 191],
+        [6, 337, 100, 189, 488, 28, 399, 506, 467, 330, 195, 311],
+        [304, 439, 207, 79, 455, 438, 209, 225, 298, 449, 400, 9],
+        [251, 265, 318, 500, 477, 348, 25, 450, 123, 504, 33, 378],
+        [184, 433, 501, 501, 501, 100, 315, 433, 51, 184, 433, 306]]
+
+    def test_decode_donates_the_cache_and_serves_the_same_ids(self):
+        import jax
+        cfg, m, params = self._setup()
+        rng = np.random.default_rng(3)
+        reqs = [Request(req_id=i, prompt=rng.integers(0, cfg.vocab_size, 8)
+                        .astype(np.int32), prompt_len=8, max_new_tokens=12,
+                        arrival_time=0.0) for i in range(6)]
+        backend = ExecutedBackend(cfg, m, params, max_batch=4, buf_len=16,
+                                  fmt="float32")
+        eng = ServeEngine(cfg, fmt="float32", mode="continuous",
+                          backend=backend, batch_policy=SlotCountPolicy(
+                              max_batch=4, max_prefill_batch=2))
+        rep = eng.run(reqs)
+        assert [r.generated for r in rep.requests] == self.SERVED_IDS
+        old = backend.cache
+        backend.decode_step(DecodeBatch(slots=[0], requests=[reqs[0]],
+                                        cache_lens=[20]))
+        assert all(a.is_deleted() for a in jax.tree.leaves(old))
+        assert not any(a.is_deleted()
+                       for a in jax.tree.leaves(backend.cache))
+
 
 # ---------------------------------------------------------------------------
 # DVFS device states
