@@ -1,11 +1,13 @@
 """One run of one cell: set-up, the open-loop window, the check.
 
 The cell is found by name in ``BENCHMARK.json``; its parts are files
-found by name: the configuration ``configs/<config>.json``, the mix
-``traffic/<traffic>.json``, the engine settings and rate
+found by name: the configuration ``configs/<config>.json``, the
+architecture it names ``architectures/<architecture>.py`` (the
+program's ``ModelConfig``, the plain reference and the model FLOPs),
+the mix ``traffic/<traffic>.json``, the engine settings and rate
 ``cells/<cell>.json``, and one reader per per-layer metric
-``metrics/<metric>.py``. Adding any of them takes new files and
-entries, and no edit here.
+``metrics/<metric>.py``. Adding any of them, an architecture included,
+takes new files and entries, and no edit here.
 
 The system under test is driven only through its public calls:
 ``build_model``, ``Model.quantize``, ``ServeEngine(execute=True)`` and
@@ -25,6 +27,7 @@ import shutil
 import sys
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -39,8 +42,9 @@ DRAIN_LIMIT_S = 60.0
 
 
 class Refused(RuntimeError):
-    """The run cannot measure anything: no chip, an unknown chip, or a
-    cell that does not exist."""
+    """The run cannot measure anything: no chip, an unknown chip, a
+    cell that does not exist, or a configuration that names no
+    architecture there is a file for."""
 
 
 @dataclasses.dataclass
@@ -52,11 +56,39 @@ class Cell:
     settings: Dict
     end_to_end: List[Dict]
     per_layer: List[Dict]
+    # the module of architectures/<architecture>.py: model_config,
+    # logits_at, prefill_flops, decode_flops
+    arch: ModuleType
 
 
 def _json(path: Path) -> Dict:
     with open(path) as f:
         return json.load(f)
+
+
+def _module(path: Path, name: str) -> ModuleType:
+    """The Python file at ``path``, loaded as module ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def architecture(config: Dict, root: Path = ROOT) -> ModuleType:
+    """The architecture module the configuration names under
+    ``architecture_module``, ``chipbench/architectures/<module>.py``.
+    There is no default. (The key is not Hugging Face's
+    ``architectures``, which names the published class.)"""
+    name = config.get("architecture_module")
+    if not isinstance(name, str) or not name:
+        raise Refused(f"configuration {config.get('name')!r} names no "
+                      f"architecture module")
+    path = root / "chipbench" / "architectures" / f"{name}.py"
+    if not path.is_file():
+        raise Refused(f"configuration {config.get('name')!r} names "
+                      f"architecture module {name!r}, and there is no "
+                      f"{path}")
+    return _module(path, "chipbench_arch_" + name.replace(".", "_"))
 
 
 def load_cell(name: str, root: Path = ROOT) -> Cell:
@@ -81,7 +113,7 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
                  if (name in m["workloads"] if "workloads" in m
                      else m["moves"] in reported)]
     return Cell(name, int(wl["chips"]), config, mix, settings, e2e,
-                per_layer)
+                per_layer, architecture(config, root))
 
 
 # ---------------------------------------------------------------------------
@@ -142,23 +174,6 @@ class CompileCounter:
         jax.monitoring.register_event_listener(on_event)
 
 
-def model_config(config: Dict):
-    """The program's ModelConfig for a configuration file."""
-    from repro.configs.base import ModelConfig
-    return ModelConfig(
-        name=config["name"], family="dense",
-        num_layers=int(config["num_hidden_layers"]),
-        d_model=int(config["hidden_size"]),
-        num_heads=int(config["num_attention_heads"]),
-        num_kv_heads=int(config["num_key_value_heads"]),
-        head_dim=int(config["head_dim"]),
-        d_ff=int(config["intermediate_size"]),
-        vocab_size=int(config["vocab_size"]),
-        rope_theta=float(config["rope_theta"]),
-        use_bias=bool(config["attention_bias"]),
-        source=config["source"])
-
-
 @dataclasses.dataclass
 class Served:
     """The system under test, built and warmed, with what the check
@@ -178,7 +193,7 @@ def build(cell: Cell, seed: int, on_tpu: bool, device_spec) -> Served:
     from repro.serving.engine import ServeEngine
     from chipbench import weights
 
-    cfg = model_config(cell.config)
+    cfg = cell.arch.model_config(cell.config)
     prec = cell.config["precision"]
     model = build_model(cfg, prec["fmt"], use_pallas_kernels=on_tpu)
     if model.policy.is_quantized and prec["fmt"] == "int8":
@@ -265,11 +280,13 @@ class Records:
 
 class _Tracer:
     """The profiler over a slice of the window: from the first step end
-    past ``at_s`` until ``length_s`` later."""
+    past ``at_s`` until ``length_s`` later. ``start_s`` and ``stop_s``
+    are how long starting and stopping it held the host loop."""
 
     def __init__(self, at_s: float, length_s: float, out_dir: Path):
         self.at, self.length, self.dir = at_s, length_s, out_dir
         self.t0 = self.t1 = None
+        self.start_s = self.stop_s = 0.0
 
     def poll(self, now: float, clock) -> None:
         import jax
@@ -282,6 +299,7 @@ class _Tracer:
             opts.host_tracer_level = 1
             jax.profiler.start_trace(str(self.dir), profiler_options=opts)
             self.t0 = clock()
+            self.start_s = self.t0 - now
         elif (self.t0 is not None and self.t1 is None
               and now >= self.t0 + self.length):
             self.close(clock)
@@ -291,6 +309,7 @@ class _Tracer:
         if self.t0 is not None and self.t1 is None:
             self.t1 = clock()
             jax.profiler.stop_trace()
+            self.stop_s = clock() - self.t1
 
 
 def run_window(served: Served, cell: Cell, plan, seconds: float,
@@ -392,6 +411,13 @@ def counted_ids(rec: Records) -> List[int]:
     return [i for i, p in enumerate(rec.plan) if p.counted]
 
 
+def ttfts(rec: Records) -> List[float]:
+    """Seconds from due to first token of each counted request; one
+    that never got its first token counts as the run's close."""
+    return [rec.first.get(i, rec.closed_s) - rec.plan[i].due_s
+            for i in counted_ids(rec)]
+
+
 def end_to_end(cell: Cell, rec: Records) -> Dict[str, float]:
     """Every end-to-end metric this cell reports, but ``setup_s``. A
     request that never got its first or last token is stamped when the
@@ -402,8 +428,7 @@ def end_to_end(cell: Cell, rec: Records) -> Dict[str, float]:
     first = {i: rec.first.get(i, rec.closed_s) for i in ids}
     done = {i: rec.done.get(i, rec.closed_s) for i in ids}
     if "ttft_p90_ms" in names:
-        out["ttft_p90_ms"] = 1e3 * stats.percentile(
-            [first[i] - rec.plan[i].due_s for i in ids], 90)
+        out["ttft_p90_ms"] = 1e3 * stats.percentile(ttfts(rec), 90)
     if "tpot_p90_ms" in names:
         out["tpot_p90_ms"] = 1e3 * stats.percentile(
             [(done[i] - first[i])
@@ -459,17 +484,16 @@ def sample_rows(rec: Records, sample: List[int], vocab: int):
     return rows, firsts, served, bad
 
 
-def gap_stats(config: Dict, layout: Dict, key, rows, firsts,
+def gap_stats(cell: Cell, layout: Dict, key, rows, firsts,
               candidates) -> List[Dict[str, float]]:
     """For each column of ``candidates`` (one (positions, T) array of
     token ids per row): by how much the reference's logit of that token
     lies below the reference's best at its position, as the widest gap
     over every checked position, the mean gap, and the share of
     positions where the token is not the reference's first choice."""
-    from chipbench import reference
     allg = np.concatenate(
-        [mx[:, None] - lt for mx, lt in reference.logits_at(
-            config, layout, key, rows, candidates, firsts)], axis=0)
+        [mx[:, None] - lt for mx, lt in cell.arch.logits_at(
+            cell.config, layout, key, rows, candidates, firsts)], axis=0)
     return [{"widest": float(allg[:, j].max()),
              "mean": float(allg[:, j].mean()),
              "off_first": float((allg[:, j] > 0).mean())}
@@ -482,7 +506,7 @@ def served_gaps(cell: Cell, layout: Dict, key, vocab: int, rec: Records,
     best logit (mean and widest gap), and the served tokens that are
     missing or out of range."""
     rows, firsts, served, bad = sample_rows(rec, sample, vocab)
-    g = gap_stats(cell.config, layout, key, rows, firsts,
+    g = gap_stats(cell, layout, key, rows, firsts,
                   [t[:, None] for t in served])[0]
     return {"widest_gap": g["widest"], "mean_gap": g["mean"],
             "off_first": g["off_first"], "bad_tokens": float(bad),
@@ -505,11 +529,8 @@ def read_metric(name: str, ctx: Context, root: Path = ROOT
                 ) -> Optional[float]:
     """The value of per-layer metric ``name``, from its reader
     ``chipbench/metrics/<name>.py``; None where it found nothing."""
-    path = root / "chipbench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    mod = _module(root / "chipbench" / "metrics" / f"{name}.py",
+                  "chipbench_metric_" + name.replace(".", "_"))
     value = mod.read(ctx)
     return None if value is None else float(value)
 
@@ -541,6 +562,9 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     rec = run_window(served, cell, plan, seconds, counter, tracer)
     stats_ = dev.memory_stats() or {}
     peak = int(stats_.get("peak_bytes_in_use", 0))
+    # what the served state holds at the close, beside the process's
+    # peak (which set-up may set)
+    in_use = int(stats_.get("bytes_in_use", 0))
     reduced = None
     if tracer is not None and tracer.t0 is not None:
         reduced = tracing.reduce_dir(tracer.dir, tracer.t1 - tracer.t0)
@@ -589,10 +613,14 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
         "seed": seed, "seconds": seconds, "setup_s": setup_s,
         "window_s": rec.window_s, "steps": len(rec.steps),
         "decode_calls": rec.decode_calls, "compiles_in_window": rec.compiles,
+        "memory_in_use_bytes": in_use,
         "generator_late_p50_ms": 1e3 * stats.percentile(late, 50),
         "generator_late_max_ms": 1e3 * max(late),
         "end_to_end": e2e,
     }
+    if tracer is not None:
+        out["run"]["trace_start_s"] = tracer.start_s
+        out["run"]["trace_stop_s"] = tracer.stop_s
     out["run"]["tokens_checked"] = int(checks["tokens_checked"])
     out["run"]["widest_gap"] = checks["widest_gap"]
     out["run"]["off_first"] = checks["off_first"]

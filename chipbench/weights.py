@@ -58,7 +58,8 @@ def _paths(tree, prefix=""):
             yield p, v
 
 
-def _set(tree: Dict, path: str, value) -> None:
+def put(tree: Dict, path: str, value) -> None:
+    """Set ``value`` at the slash-separated ``path`` of a nested dict."""
     *head, last = path.split("/")
     for k in head:
         tree = tree.setdefault(k, {})
@@ -87,7 +88,7 @@ def make(model, key) -> Dict[str, Any]:
                                              sd.dtype))(jnp.arange(n))
             else:
                 v = _draw(k, name, sd.shape, sd.dtype)
-            _set(out, path, v)
+            put(out, path, v)
         return out
 
     return jax.jit(build)(key)

@@ -4,6 +4,7 @@ import json
 import re
 import shutil
 import sys
+import time
 from pathlib import Path
 from types import SimpleNamespace
 
@@ -69,9 +70,62 @@ def test_chat_cell_reads_its_parts():
                                                 "compiles.chat"}
 
 
+def test_offline_cell_reads_its_parts():
+    """The Mistral offline cell reports tokens/s and set-up alone, its
+    five readers attach, and the chat cell's lists stay as they were."""
+    c = harness.load_cell("mistral-7b-16L-int8.offline")
+    assert [m["name"] for m in c.end_to_end] == ["tokens_per_s", "setup_s"]
+    assert [m["name"] for m in c.per_layer] == [
+        "decode_step_ms.offline", "decode_batch_mean", "step_mfu",
+        "quant_matmul_roofline", "compiles.offline"]
+    assert all(m["moves"] == "tokens_per_s" for m in c.per_layer)
+    assert c.config["architecture_module"] == "dense"
+    assert c.config["precision"]["fmt"] == "int8"
+    assert c.mix["arrival"] == "all_at_once"
+    chat = harness.load_cell("stablelm-1.6b-bf16.chat")
+    assert {m["name"] for m in chat.end_to_end} == {
+        "tpot_p90_ms", "setup_s"}
+    assert not {m["name"] for m in chat.per_layer} & {
+        m["name"] for m in c.per_layer}
+
+
+@pytest.mark.parametrize("name", ["stablelm-1.6b-bf16",
+                                  "mistral-7b-16L-int8"])
+def test_every_configuration_names_its_architecture_module(name):
+    cfg = json.loads((ROOT / "chipbench" / "configs" / f"{name}.json")
+                     .read_text())
+    assert cfg["architecture_module"] == "dense"
+    mc = harness.architecture(cfg).model_config(cfg)
+    assert (mc.num_layers, mc.d_model, mc.vocab_size) == (
+        cfg["num_hidden_layers"], cfg["hidden_size"], cfg["vocab_size"])
+    chat = harness.load_cell("stablelm-1.6b-bf16.chat")
+    assert [m["name"] for m in chat.end_to_end] == [
+        "tpot_p90_ms", "setup_s"]
+    assert [m["name"] for m in chat.per_layer] == [
+        "queue_wait_p90_ms", "prefill_ms", "decode_step_ms.chat",
+        "compiles.chat", "decode_host_ms.chat", "cache_insert_ms",
+        "engine_wait_p90_ms", "ttft_p90_ms.chat"]
+
+
 def test_unknown_cell_is_refused():
     with pytest.raises(harness.Refused):
         harness.load_cell("no-such-cell")
+
+
+@pytest.mark.parametrize("arch", [None, "", "no-such-architecture"])
+def test_a_configuration_without_a_known_architecture_is_refused(
+        tmp_path, arch):
+    shutil.copytree(ROOT / "chipbench", tmp_path / "chipbench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
+    path = tmp_path / "chipbench" / "configs" / "stablelm-1.6b-bf16.json"
+    cfg = json.loads(path.read_text())
+    cfg.pop("architecture_module")
+    if arch is not None:
+        cfg["architecture_module"] = arch
+    path.write_text(json.dumps(cfg))
+    with pytest.raises(harness.Refused, match="architecture"):
+        harness.load_cell("stablelm-1.6b-bf16.chat", root=tmp_path)
 
 
 def test_a_new_cell_takes_only_new_files(tmp_path):
@@ -103,16 +157,104 @@ def test_a_new_cell_takes_only_new_files(tmp_path):
     bench["end_to_end"][0]["workloads"].append("stablelm-1.6b-8L.bursty")
     bench["per_layer"].append({"name": "steps_seen", "unit": "count",
                                "better": "higher", "source": "host_clock",
-                               "layer": "scheduler", "moves": "ttft_p90_ms",
+                               "layer": "scheduler", "moves": "tpot_p90_ms",
                                "workloads": ["stablelm-1.6b-8L.bursty"]})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
 
     c = harness.load_cell("stablelm-1.6b-8L.bursty", root=tmp_path)
     assert c.config["num_hidden_layers"] == 8
-    assert [m["name"] for m in c.end_to_end] == ["ttft_p90_ms", "setup_s"]
+    assert [m["name"] for m in c.end_to_end] == ["tpot_p90_ms", "setup_s"]
     assert [m["name"] for m in c.per_layer] == ["steps_seen"]
     plan = traffic.generate(c.mix, c.settings, 9, 10, 100)
     assert sum(p.counted for p in plan) == 30
     assert all(200 <= len(p.prompt) <= 400 for p in plan)
     ctx = SimpleNamespace(records=SimpleNamespace(steps=[1, 2, 3]))
     assert harness.read_metric("steps_seen", ctx, root=tmp_path) == 3.0
+
+
+# A toy architecture: a dense decoder whose configuration file names its
+# sizes as GPT-2 does, so that only its own module can read them.
+TOY = """
+from chipbench.architectures import dense
+
+CALLS = []
+
+
+def _dense(config):
+    return dict(config, num_hidden_layers=config["n_layer"],
+                hidden_size=config["n_embd"],
+                num_attention_heads=config["n_head"],
+                num_key_value_heads=config["n_head"],
+                head_dim=config["n_embd"] // config["n_head"],
+                intermediate_size=4 * config["n_embd"])
+
+
+def model_config(config):
+    CALLS.append("model_config")
+    return dense.model_config(_dense(config))
+
+
+def logits_at(config, lay, key, rows, targets, first):
+    CALLS.append("logits_at")
+    return dense.logits_at(_dense(config), lay, key, rows, targets, first)
+
+
+def prefill_flops(config, prompt_len):
+    CALLS.append("prefill_flops")
+    return dense.prefill_flops(_dense(config), prompt_len)
+
+
+def decode_flops(config, prompt_len, generated):
+    return dense.decode_flops(_dense(config), prompt_len, generated)
+"""
+
+
+def test_a_new_architecture_takes_only_new_files(tmp_path):
+    """A configuration naming an architecture that exists only as a new
+    file ``architectures/<name>.py`` loads, builds, runs and is checked
+    through that file, with no other edit."""
+    import jax
+    shutil.copytree(ROOT / "chipbench", tmp_path / "chipbench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    bench = json.loads(json.dumps(BENCH))
+    cb = tmp_path / "chipbench"
+    (cb / "architectures" / "toy.py").write_text(TOY)
+    (cb / "configs" / "toy.json").write_text(json.dumps({
+        "name": "toy", "source": "x", "architecture_module": "toy",
+        "n_layer": 2, "n_embd": 64, "n_head": 4, "vocab_size": 256,
+        "rope_theta": 1e4, "attention_bias": False, "rms_norm_eps": 1e-6,
+        "precision": {"fmt": "bfloat16"}}))
+    (cb / "traffic" / "tiny_offline.json").write_text(json.dumps({
+        "arrival": "all_at_once", "n_requests": 24,
+        "prompt_tokens": {"dist": "log_uniform", "min": 5, "max": 16},
+        "output_tokens": {"dist": "log_uniform", "min": 4, "max": 12}}))
+    (cb / "cells" / "toy.tiny_offline.json").write_text(json.dumps({
+        "max_batch": 4, "max_prefill_batch": 2, "buf_len": 32,
+        "policy": "slot_count", "check_requests": 4,
+        "limits": {"mean_gap": 0.01}}))
+    bench["configs"].append({"name": "toy", "source": "x",
+                             "file": "chipbench/configs/toy.json",
+                             "reduced": [], "why": "x"})
+    bench["workloads"].append({"name": "toy.tiny_offline", "config": "toy",
+                               "traffic": "tiny_offline", "chips": 1,
+                               "why": "x"})
+    bench["end_to_end"].append({"name": "tokens_per_s", "unit": "tokens/s",
+                                "better": "higher", "bound": 0.05,
+                                "source": "host_clock",
+                                "workloads": ["toy.tiny_offline"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    c = harness.load_cell("toy.tiny_offline", root=tmp_path)
+    assert c.arch.__file__ == str(cb / "architectures" / "toy.py")
+    out = harness.run(c, 2 ** 33 + 7, 1.0, False, time.perf_counter(),
+                      jax.devices()[0], 1, tmp_path / "out")
+    assert out["correct"], out["checks"]
+    assert set(out["metrics"]) == {"tokens_per_s", "setup_s"}
+    assert c.arch.CALLS == ["model_config", "logits_at"]
+    rec = SimpleNamespace(requests=[SimpleNamespace(
+        req_id=0, prompt_len=8, generated=[1, 2, 3])], first={0: 0.1},
+        window_s=1.0)
+    ctx = SimpleNamespace(cell=c, records=rec,
+                          peaks={"bf16_flops_per_s": 1e12})
+    assert harness.read_metric("step_mfu", ctx, root=tmp_path) > 0
+    assert c.arch.CALLS[-1] == "prefill_flops"

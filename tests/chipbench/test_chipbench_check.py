@@ -36,7 +36,8 @@ def _cell(fmt, arrival="poisson", **config):
            if arrival == "poisson" else
            [{"name": "tokens_per_s", "unit": "tokens/s"}])
     return harness.Cell(f"tiny-{fmt}.{arrival}", 1, cfg, mix, settings,
-                        e2e + [{"name": "setup_s", "unit": "s"}], [])
+                        e2e + [{"name": "setup_s", "unit": "s"}], [],
+                        harness.architecture(cfg))
 
 
 def _run(cell, seed=2 ** 34 + 5):
@@ -56,7 +57,10 @@ def test_sound_run_is_correct(fmt, arrival):
         fmt, arrival).end_to_end}
 
 
-def test_token_altered_where_produced_is_not_correct(monkeypatch):
+def _alter_tokens(monkeypatch, lanes=1):
+    """Every fifth decode step, the token of its first ``lanes`` lanes
+    (None: all) is altered where it is produced, in the request and in
+    the next step's input."""
     from repro.serving.backend import ExecutedBackend
     real = ExecutedBackend._execute_decode
     calls = [0]
@@ -65,18 +69,16 @@ def test_token_altered_where_produced_is_not_correct(monkeypatch):
         real(self, batch)
         calls[0] += 1
         if calls[0] % 5 == 0:
-            slot, req = batch.slots[0], batch.requests[0]
-            bad = (req.generated[-1] + 1) % self.cfg.vocab_size
-            req.generated[-1] = bad
-            self.slot_tokens = self.slot_tokens.at[slot, 0].set(bad)
+            for slot, req in list(zip(batch.slots, batch.requests))[:lanes]:
+                bad = (req.generated[-1] + 1) % self.cfg.vocab_size
+                req.generated[-1] = bad
+                self.slot_tokens = self.slot_tokens.at[slot, 0].set(bad)
 
     monkeypatch.setattr(ExecutedBackend, "_execute_decode", altered)
-    out = _run(_cell("bfloat16"))
-    assert not out["correct"]
-    assert out["checks"]["mean_gap"]["value"] > LIMIT
 
 
-def test_step_returning_its_state_unchanged_is_not_correct(monkeypatch):
+def _freeze_state(monkeypatch):
+    """The decode step returns its cache unchanged."""
     from repro.models.api import Model
     real = Model.decode_step
 
@@ -85,7 +87,33 @@ def test_step_returning_its_state_unchanged_is_not_correct(monkeypatch):
         return logits, cache
 
     monkeypatch.setattr(Model, "decode_step", frozen)
+
+
+def test_token_altered_where_produced_is_not_correct(monkeypatch):
+    _alter_tokens(monkeypatch)
     out = _run(_cell("bfloat16"))
+    assert not out["correct"]
+    assert out["checks"]["mean_gap"]["value"] > LIMIT
+
+
+def test_step_returning_its_state_unchanged_is_not_correct(monkeypatch):
+    _freeze_state(monkeypatch)
+    out = _run(_cell("bfloat16"))
+    assert not out["correct"]
+    assert out["checks"]["mean_gap"]["value"] > LIMIT
+
+
+@pytest.mark.parametrize("fault", [
+    lambda mp: _alter_tokens(mp, lanes=None), _freeze_state],
+    ids=["token_altered", "state_unchanged"])
+def test_int8_offline_run_with_a_fault_is_not_correct(monkeypatch, fault):
+    """An offline cell's path (int8 weights, every request due at
+    once, every lane live) fails the check under each fault it can
+    have. The check samples requests, so the altered tokens are in
+    every lane: a fault in one lane of a full batch is caught only where
+    the sample holds that lane's requests."""
+    fault(monkeypatch)
+    out = _run(_cell("int8", "all_at_once"))
     assert not out["correct"]
     assert out["checks"]["mean_gap"]["value"] > LIMIT
 
@@ -98,3 +126,24 @@ def test_control_reads_above_the_limit():
     for seed in (3, 2 ** 36 + 1, 77):
         r = control.one_seed(cell, seed, 2.0, jax.devices()[0], counter)
         assert r["program"]["mean"] < LIMIT < r["control"]["mean"], r
+
+
+@pytest.mark.parametrize("fmt", ["int8", "nf4"])
+def test_control_quantizes_a_layer_at_a_time_as_the_program_does(fmt):
+    """The control's parameters, drawn and quantized a layer at a time,
+    are those of quantizing the whole tree at once, bit for bit."""
+    from repro.models import build_model
+    from chipbench import weights
+    cfg = json.loads((DATA / "tiny-int8.json").read_text())
+    model = build_model(harness.architecture(cfg).model_config(cfg), fmt)
+    key = weights.seed_key(2 ** 35 + 11)
+    want = model.quantize(weights.make(model, key))
+    got = control.lower_params(model, key)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    assert type(got["layers"]["mlp"]["w_up"]).__name__ == {
+        "int8": "Int8Weight", "nf4": "NF4Weight"}[fmt]
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        assert (a.dtype, a.shape) == (b.dtype, b.shape), path
+        assert (jax.device_get(a).tobytes()
+                == jax.device_get(b).tobytes()), path

@@ -1,4 +1,5 @@
-"""Operations and bytes from shapes, at the cells' widths."""
+"""Operations and bytes from shapes, at the cells' widths: the dense
+architecture's model FLOPs and the int8 kernel's roofline."""
 import json
 import sys
 from pathlib import Path
@@ -9,6 +10,7 @@ sys.path.insert(0, str(ROOT))
 import pytest  # noqa: E402
 
 from chipbench import flops, peaks  # noqa: E402
+from chipbench.architectures import dense  # noqa: E402
 
 
 def _config(name):
@@ -24,30 +26,30 @@ V5E = peaks.PEAKS["TPU v5 lite"]
 def test_matmul_params_at_published_widths():
     # 16 layers x (4096*(4096+1024+1024) + 4096*4096 + 3*4096*14336)
     # plus the 4096 x 32768 output head
-    assert flops.matmul_params(MISTRAL) == 16 * 218103808 + 134217728
+    assert dense.matmul_params(MISTRAL) == 16 * 218103808 + 134217728
     # stablelm: 24 x (4 * 2048^2 + 3 * 2048 * 5632) + 2048 * 100352
-    assert flops.matmul_params(STABLELM) == 24 * 51380224 + 205520896
+    assert dense.matmul_params(STABLELM) == 24 * 51380224 + 205520896
 
 
 def test_decode_token_flops():
     per_ctx = 4 * 16 * 32 * 128
-    assert flops.decode_token_flops(MISTRAL, 300) == (
-        2 * flops.matmul_params(MISTRAL) + per_ctx * 300)
-    assert flops.decode_token_flops(MISTRAL, 300) / 1e9 == pytest.approx(
+    assert dense.decode_token_flops(MISTRAL, 300) == (
+        2 * dense.matmul_params(MISTRAL) + per_ctx * 300)
+    assert dense.decode_token_flops(MISTRAL, 300) / 1e9 == pytest.approx(
         7.33, abs=0.01)
 
 
 def test_decode_flops_sums_the_steps():
-    want = sum(flops.decode_token_flops(STABLELM, 100 + k)
+    want = sum(dense.decode_token_flops(STABLELM, 100 + k)
                for k in range(1, 50))
-    assert flops.decode_flops(STABLELM, 100, 50) == want
-    assert flops.decode_flops(STABLELM, 100, 1) == 0
+    assert dense.decode_flops(STABLELM, 100, 50) == want
+    assert dense.decode_flops(STABLELM, 100, 1) == 0
 
 
 def test_prefill_flops_counts_the_head_once():
     p = 128
-    layers = flops.matmul_params(STABLELM) - 2048 * 100352
-    assert flops.prefill_flops(STABLELM, p) == (
+    layers = dense.matmul_params(STABLELM) - 2048 * 100352
+    assert dense.prefill_flops(STABLELM, p) == (
         2 * layers * p + 4 * 24 * 32 * 64 * p * (p + 1) // 2
         + 2 * 2048 * 100352)
 

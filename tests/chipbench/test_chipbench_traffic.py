@@ -1,5 +1,6 @@
 """The traffic generator: deterministic per seed, the same work for
 every seed, in another order."""
+import json
 import sys
 from collections import Counter
 from pathlib import Path
@@ -94,3 +95,15 @@ def test_exponential_gaps_fill_the_segment():
     g = traffic.exponential_gaps(200, 50.0)
     assert g.sum() == pytest.approx(50.0)
     assert np.all(g > 0)
+
+
+def test_offline_mix_keeps_the_means_of_its_source():
+    """The offline mix's lengths are log-uniform with the means its
+    source states, within 2%."""
+    mix = json.loads((ROOT / "chipbench" / "traffic" / "offline.json")
+                     .read_text())
+    for kind, want in mix["source_mean_tokens"].items():
+        spec = mix[f"{kind}_tokens"]
+        got = traffic.log_uniform_quantiles(spec["min"], spec["max"],
+                                            mix["n_requests"]).mean()
+        assert abs(got / want - 1) < 0.02, (kind, got, want)

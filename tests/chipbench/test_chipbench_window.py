@@ -11,10 +11,12 @@ sys.path.insert(0, str(ROOT))
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
-from chipbench import flops, harness, peaks, stats, traffic  # noqa: E402
+from chipbench import harness, peaks, stats, traffic  # noqa: E402
+from chipbench.architectures import dense  # noqa: E402
 
 with open(ROOT / "chipbench" / "configs" / "stablelm-1.6b-bf16.json") as f:
     STABLELM = json.load(f)
+DENSE = harness.architecture(STABLELM)
 
 
 def _reader(name):
@@ -51,7 +53,7 @@ def _records(n=40):
 
 def _cell(names):
     return harness.Cell("x", 1, STABLELM, {"arrival": "poisson"}, {},
-                        [{"name": n} for n in names], [])
+                        [{"name": n} for n in names], [], DENSE)
 
 
 def test_percentile_is_numpy_linear():
@@ -95,7 +97,7 @@ def test_unfinished_counts_requests_never_completed():
     tpot[3] = 1e3 * (1000.0 - rec.first[3]) / 10
     assert out["tpot_p90_ms"] == pytest.approx(np.percentile(tpot, 90))
     offline = harness.Cell("x", 1, STABLELM, {"arrival": "all_at_once"},
-                           {}, [], [])
+                           {}, [], [], DENSE)
     assert harness.unfinished(offline, rec) == 0
 
 
@@ -106,6 +108,9 @@ def test_host_clock_readers():
     waits = [10.0 * i for i in range(40)]
     assert _reader("queue_wait_p90_ms")(ctx) == pytest.approx(
         np.percentile(waits, 90))
+    ttft = [10.0 * i + 5.0 for i in range(40)]
+    assert _reader("ttft_p90_ms.chat")(ctx) == pytest.approx(
+        np.percentile(ttft, 90))
     assert _reader("decode_batch_mean")(ctx) == 100 / 25
     rec.compiles = 3
     assert _reader("compiles.chat")(ctx) == 3
@@ -116,7 +121,24 @@ def test_step_mfu_from_config_shapes():
     rec = _records()
     ctx = harness.Context(_cell([]), rec, peaks.peaks_for("TPU v5 lite"),
                           None)
-    work = 42 * (flops.prefill_flops(STABLELM, 8)
-                 + flops.decode_flops(STABLELM, 8, 11))
+    work = 42 * (dense.prefill_flops(STABLELM, 8)
+                 + dense.decode_flops(STABLELM, 8, 11))
     assert _reader("step_mfu")(ctx) == pytest.approx(
         100 * work / (20.0 * 197e12))
+
+
+def test_tracer_times_how_long_it_held_the_loop(tmp_path):
+    """The profiler runs over its slice of the window; how long its
+    start and its stop held the loop is kept, and the directory holds
+    the slice's trace."""
+    times = iter([1.25, 3.5, 4.0])
+    tracer = harness._Tracer(1.0, 2.0, tmp_path / "trace")
+    tracer.poll(0.5, lambda: next(times))
+    assert tracer.t0 is None
+    tracer.poll(1.0, lambda: next(times))     # the start returns at 1.25
+    assert (tracer.t0, tracer.start_s) == (1.25, 0.25)
+    tracer.poll(3.0, lambda: next(times))
+    assert tracer.t1 is None
+    tracer.poll(3.5, lambda: next(times))     # the stop returns at 4.0
+    assert (tracer.t1, tracer.stop_s) == (3.5, 0.5)
+    assert len(list((tmp_path / "trace").rglob("*.xplane.pb"))) == 1
