@@ -319,7 +319,8 @@ class ContinuousBatcher:
 #: batch-axis position of each cache leaf (for slot insert/evict):
 #: attention K/V and SSM state stack layers on axis 0, so the request
 #: batch is axis 1; per-slot position counters are batch-major.
-CACHE_BATCH_AXIS = {"k": 1, "v": 1, "ssm_state": 1, "conv": 1,
+CACHE_BATCH_AXIS = {"k": 1, "v": 1, "k_scale": 1, "v_scale": 1,
+                    "ssm_state": 1, "conv": 1,
                     "shared_k": 1, "shared_v": 1, "enc_k": 1, "enc_v": 1,
                     "slot_pos": 0, "pos": 0}
 

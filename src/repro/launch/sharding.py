@@ -151,9 +151,9 @@ def cache_specs(cfg: ModelConfig, abstract_cache, mesh: Mesh,
 
     def spec_for(key: str, leaf) -> P:
         shp = leaf.shape
-        if key in ("k", "v"):                 # (L, B, W, kv, hd)
-            w = "model" if shp[2] % msz == 0 else None
-            return P(None, b_ax, w, None, None)
+        if key in ("k", "v"):                 # (L, B, kv, W, hd)
+            w = "model" if shp[3] % msz == 0 else None
+            return P(None, b_ax, None, w, None)
         if key in ("shared_k", "shared_v"):   # (sites, B, W, kv, hd)
             w = "model" if shp[2] % msz == 0 else None
             return P(None, b_ax, w, None, None)
@@ -166,9 +166,9 @@ def cache_specs(cfg: ModelConfig, abstract_cache, mesh: Mesh,
         if key == "conv":                     # (L, B, K-1, C)
             c = "model" if shp[3] % msz == 0 else None
             return P(None, b_ax, None, c)
-        if key in ("k_scale", "v_scale"):     # (L, B, W, kv)
-            w = "model" if shp[2] % msz == 0 else None
-            return P(None, b_ax, w, None)
+        if key in ("k_scale", "v_scale"):     # (L, B, kv, W)
+            w = "model" if shp[3] % msz == 0 else None
+            return P(None, b_ax, None, w)
         if key == "slot_pos":                 # (B, W)
             w = "model" if shp[1] % msz == 0 else None
             return P(b_ax, w)

@@ -258,12 +258,13 @@ class Model:
         return buf_len or (S + 32)
 
     def _kv_cache_from_prefill(self, kv, B, S, buf, lengths):
-        k, v = kv                              # (L, B, S, Kv, hd)
+        # (L, B, S, Kv, hd) -> the cache's head-major (L, B, Kv, W, hd)
+        k, v = (a.swapaxes(2, 3) for a in kv)
         W = buf
         if S >= W:
-            k, v = k[:, :, S - W:], v[:, :, S - W:]
+            k, v = k[:, :, :, S - W:], v[:, :, :, S - W:]
         else:
-            pad = [(0, 0), (0, 0), (0, W - S), (0, 0), (0, 0)]
+            pad = [(0, 0), (0, 0), (0, 0), (0, W - S), (0, 0)]
             k, v = jnp.pad(k, pad), jnp.pad(v, pad)
         cache = {
             "slot_pos": slot_positions_after_prefill(W, lengths, S),

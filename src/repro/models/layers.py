@@ -112,25 +112,35 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     """One token per row against the cache as it was before this step,
     plus the token's own K/V, under one softmax.
 
-    q: (B, 1, H, hd); k/v: (B, W, Kv, hd), read where they lie (never
-    written here); k_new/v_new: (B, 1, Kv, hd); ``allow``: (B, W) cache
-    slots the token may see, excluding the slot it will overwrite.
+    q: (B, 1, H, hd); k/v: (B, Kv, W, hd), head-major as the cache
+    stores them and read where they lie (never written here);
+    k_new/v_new: (B, 1, Kv, hd); ``allow``: (B, W) cache slots the token
+    may see, excluding the slot it will overwrite.
     The same keys as :func:`attention` over the cache with the token
     written into its slot; only the summation order differs.
     Returns (B, 1, H, hd).
     """
-    B, S, H, hd = q.shape
-    Kv = k.shape[2]
-    qg = q.reshape(B, S, Kv, H // Kv, hd)
-    s_old = _gqa_scores_einsum(qg, k) / jnp.sqrt(float(hd))   # (B,Kv,G,1,W)
-    s_new = _gqa_scores_einsum(qg, k_new) / jnp.sqrt(float(hd))  # (...,1)
-    s_old = jnp.where(allow[:, None, None, None, :], s_old, NEG_INF)
+    B, _, H, hd = q.shape
+    Kv = k.shape[1]
+    qg = q.reshape(B, Kv, H // Kv, hd)
+    k_new, v_new = k_new.swapaxes(1, 2), v_new.swapaxes(1, 2)  # (B,Kv,1,hd)
+
+    def scores(kk):                                  # (B, Kv, G, T)
+        return jnp.einsum("bkgh,bkth->bkgt", qg, kk,
+                          preferred_element_type=jnp.float32) \
+            / jnp.sqrt(float(hd))
+
+    def values(p, vv):                               # (B, Kv, G, hd)
+        return jnp.einsum("bkgt,bkth->bkgh", p.astype(vv.dtype), vv,
+                          preferred_element_type=jnp.float32)
+
+    s_old = jnp.where(allow[:, None, None, :], scores(k), NEG_INF)
+    s_new = scores(k_new)                            # (B, Kv, G, 1)
     m = jnp.maximum(jnp.max(s_old, axis=-1, keepdims=True), s_new)
     e_old, e_new = jnp.exp(s_old - m), jnp.exp(s_new - m)
     denom = jnp.sum(e_old, axis=-1, keepdims=True) + e_new
-    out = (_gqa_values_einsum((e_old / denom).astype(v.dtype), v)
-           + _gqa_values_einsum((e_new / denom).astype(v_new.dtype), v_new))
-    return out.reshape(B, S, H, hd).astype(q.dtype)
+    out = values(e_old / denom, v) + values(e_new / denom, v_new)
+    return out.reshape(B, 1, H, hd).astype(q.dtype)
 
 
 def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
@@ -205,10 +215,15 @@ def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 def init_kv_cache(n_layers: int, batch: int, buf_len: int, n_kv: int,
                   head_dim: int, dtype=jnp.bfloat16) -> Dict[str, Any]:
     """Per-row positions: continuous batching gives every slot (batch row)
-    its own sequence, so ``pos`` is (B,) and ``slot_pos`` is (B, W)."""
+    its own sequence, so ``pos`` is (B,) and ``slot_pos`` is (B, W).
+
+    K and V are head-major, (L, B, Kv, W, hd): one layer's slice is what
+    :func:`decode_attention` reads, and in this order the TPU compiler
+    fuses the read of the slice into the attention dots instead of
+    copying it out of the stack first."""
     return {
-        "k": jnp.zeros((n_layers, batch, buf_len, n_kv, head_dim), dtype),
-        "v": jnp.zeros((n_layers, batch, buf_len, n_kv, head_dim), dtype),
+        "k": jnp.zeros((n_layers, batch, n_kv, buf_len, head_dim), dtype),
+        "v": jnp.zeros((n_layers, batch, n_kv, buf_len, head_dim), dtype),
         # absolute position held in each slot (-1 = empty)
         "slot_pos": jnp.full((batch, buf_len), -1, jnp.int32),
         "pos": jnp.zeros((batch,), jnp.int32),
@@ -231,31 +246,31 @@ def cache_write_decode(cache_layer_k, cache_layer_v, k, v, pos):
 
 def cache_write_tokens(cache: jnp.ndarray, new: jnp.ndarray,
                        slot: jnp.ndarray) -> jnp.ndarray:
-    """Write every layer's new token into a stacked cache at each row's
-    slot, in place when ``cache`` is donated.
+    """Write every layer's new token into a stacked head-major cache at
+    each row's slot, in place when ``cache`` is donated.
 
-    cache: (L, B, W, ...); new: (L, B, ...); slot: (B,).
+    cache: (L, B, Kv, W, ...); new: (L, B, Kv, ...); slot: (B,).
     A scatter into the cache makes the TPU compiler relayout the whole
-    cache (its default layout puts W in the lanes). Instead each row
-    reads the 128-slot chunk (or all W slots where W is not a multiple
-    of 128) that holds its slot, selects the token into it, and writes
-    the chunk back: tile-aligned, and no cache-sized copy. The start
-    indices are non-negative by construction; JAX's wrap of negative
-    ones would hide from the compiler that the start is a multiple of
-    128, and the writes then ran 3.7x slower on a v5e.
+    cache. Instead each row reads the 128-slot chunk (or all W slots
+    where W is not a multiple of 128) that holds its slot, selects the
+    token into it, and writes the chunk back: tile-aligned, and no
+    cache-sized copy. The start indices are non-negative by
+    construction; JAX's wrap of negative ones would hide from the
+    compiler that the start is a multiple of 128, and the writes then
+    ran 3.7x slower on a v5e.
     """
-    L, B, W = cache.shape[:3]
-    rest = cache.shape[3:]
+    L, B, Kv, W = cache.shape[:4]
+    rest = cache.shape[4:]
     chunk = 128 if W % 128 == 0 else W
-    at = jnp.arange(chunk).reshape((1, 1, chunk) + (1,) * len(rest))
+    at = jnp.arange(chunk).reshape((1, 1, 1, chunk) + (1,) * len(rest))
     tail = (0,) * len(rest)
 
     def row(b, c):
         start = slot[b] // chunk * chunk
-        idx = (0, b, start) + tail
-        old = jax.lax.dynamic_slice(c, idx, (L, 1, chunk) + rest,
+        idx = (0, b, 0, start) + tail
+        old = jax.lax.dynamic_slice(c, idx, (L, 1, Kv, chunk) + rest,
                                     allow_negative_indices=False)
-        tok = jax.lax.dynamic_slice_in_dim(new, b, 1, axis=1)[:, :, None]
+        tok = jax.lax.dynamic_slice_in_dim(new, b, 1, axis=1)[:, :, :, None]
         upd = jnp.where(at == slot[b] - start, tok.astype(c.dtype), old)
         return jax.lax.dynamic_update_slice(c, upd, idx,
                                             allow_negative_indices=False)

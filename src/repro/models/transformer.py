@@ -241,7 +241,8 @@ def decoder_decode_step(stack: Dict[str, Any], x: jnp.ndarray,
                         window: Optional[int] = None,
                         enc_kv: Optional[Tuple] = None):
     """One-token decode. x: (B, 1, D). cache: see layers.init_kv_cache
-    (per-row pos (B,) / slot_pos (B, W)).
+    (head-major K/V (L, B, Kv, W, hd), per-row pos (B,) / slot_pos
+    (B, W)).
 
     Each layer attends over its cache slice as it was before this step
     (the slot being overwritten masked out) plus the token's own K/V;
@@ -251,7 +252,7 @@ def decoder_decode_step(stack: Dict[str, Any], x: jnp.ndarray,
     """
     pos = cache["pos"]                                         # (B,)
     slot_pos = cache["slot_pos"]                               # (B, W)
-    W = cache["k"].shape[2]
+    W = cache["k"].shape[3]
     B = x.shape[0]
     slot = jnp.mod(pos, W)
     allow = (decode_attention_mask(slot_pos, pos, window)

@@ -264,6 +264,40 @@ class TestExecuted:
         assert float(cache["k"][0, 2, 0]) == 0.0
         assert float(cache["pos"][2]) == 0.0
 
+    @pytest.mark.parametrize("kv_quant", [False, True])
+    def test_insert_moves_a_prefill_row_into_the_head_major_cache(
+            self, kv_quant):
+        """A prefill row lands in its decode lane whole: K/V (L, B, Kv,
+        W, hd), int8 scales (L, B, Kv, W), positions; other lanes keep
+        theirs."""
+        import jax
+        import jax.numpy as jnp
+        from repro.batching.continuous import (CACHE_BATCH_AXIS,
+                                               insert_cache_slot)
+        from repro.models import build_model
+        cfg = get_config("minitron-8b").reduced()
+        m = build_model(cfg, fmt="float32", kv_quant=kv_quant)
+        params = m.init(jax.random.PRNGKey(0))
+        toks = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0,
+                                  cfg.vocab_size)
+        _, pcache = m.prefill(params, {"tokens": toks}, buf_len=16,
+                              lengths=jnp.array([8, 5]))
+        cache = insert_cache_slot(m.init_cache(4, 16), pcache, row=1,
+                                  slot=2)
+        L, Kv, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
+        assert cache["k"].shape == (L, 4, Kv, 16, hd)
+        if kv_quant:
+            assert cache["k_scale"].shape == (L, 4, Kv, 16)
+        assert set(cache) == set(pcache)
+        for key, val in cache.items():
+            ax, val = CACHE_BATCH_AXIS[key], np.asarray(val)
+            np.testing.assert_array_equal(
+                np.take(val, 2, ax), np.take(np.asarray(pcache[key]), 1, ax))
+            # the other lanes are as init_cache left them
+            assert not np.any(np.delete(val, 2, ax) > 0), key
+        assert int(cache["slot_pos"][2, 4]) == 4
+        assert int(cache["slot_pos"][2, 5]) == -1       # past its prompt
+
     def test_backend_kwarg_matches_legacy_execute(self):
         cfg, m, params = self._setup()
         legacy = ServeEngine(cfg, fmt="float32", mode="continuous",

@@ -25,7 +25,10 @@ class TestKVQuantCache:
         cfg, m, mq, params = self._models()
         c = mq.init_cache(2, 16)
         assert c["k"].dtype == jnp.int8
-        assert c["k_scale"].shape == c["k"].shape[:-1]
+        # head-major: (L, B, Kv, W, hd) codes, one scale per (L, B, Kv, W)
+        L, Kv, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
+        assert c["k"].shape == (L, 2, Kv, 16, hd)
+        assert c["k_scale"].shape == (L, 2, Kv, 16)
 
     def test_decode_close_to_fp_cache(self):
         cfg, m, mq, params = self._models()
